@@ -9,7 +9,7 @@ fallback* is now explicit, backend-derived, and logged once per wrapper:
 
 * ``interpret=None`` (the default everywhere) resolves through
   :class:`KernelTuning` — on TPU the Pallas kernels compile natively, so
-  interpret resolves ``False``; on CPU/GPU (where the ``pltpu`` kernels
+  interpret resolves ``False``; on the CPU (where the ``pltpu`` kernels
   have no compiled lowering) it resolves ``True`` for the dense kernels.
 * The paged decode has a second compiled option: the pure-XLA
   page-table walk in ``kernels/xla_paged.py`` (bitwise-equal to the
@@ -71,12 +71,11 @@ class KernelTuning:
 
 
 # The autotuning table: one entry per backend.  TPU keeps the larger MXU/
-# VPU-aligned blocks; CPU/GPU run the dense kernels in interpret mode only
-# under explicit request, so their block sizes matter mostly for tests.
+# VPU-aligned blocks; the CPU runs the dense kernels in interpret mode, so
+# its block sizes matter mostly for tests.
 _BACKEND_TUNING = {
     "tpu": KernelTuning(interpret=False, paged_impl="pallas"),
     "cpu": KernelTuning(),
-    "gpu": KernelTuning(),
 }
 _tuning_override: Optional[KernelTuning] = None
 

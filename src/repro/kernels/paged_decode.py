@@ -2,8 +2,8 @@
 
 The zero-copy decode hot path: instead of materializing a slot-major dense
 copy of every KV page (``serve/kvpool.py:gather_pages`` — O(B * P * page_size)
-HBM rows per layer per token), the kernel's grid walks each slot's page table
-directly.  Block ``ki`` of slot ``b`` is page ``tables[b, ki]`` of the
+HBM rows per layer per token), the kernel's grid walks each slot's page
+table directly.  Block ``ki`` of slot ``b`` is page ``tables[b, ki]`` of the
 physical pool; the ``(B, P)`` table and the per-slot lengths ride in as
 scalar-prefetch operands so the K/V block index maps can chase the table
 before the block is fetched.
@@ -19,8 +19,10 @@ block_k=page_size)`` — same online-softmax accumulator, same block order,
 same length mask, so swapping the dense gather for the page walk can never
 change logits.
 
-Grid: (batch, q_heads, pages_per_slot) — page axis innermost (sequential),
-scratch carries (m, l, acc) across a slot's pages.
+Grid: (batch, pages_per_slot) — page axis innermost (sequential), scratch
+carries (m, l, acc) across a slot's pages.  Each grid step reads one whole
+page ``(1, page_size, KV, hd)`` and the slot's ``(1, 1, H, hd)`` queries:
+both blocks end in the array's own last two dims, as the TPU requires.
 """
 from __future__ import annotations
 
@@ -32,11 +34,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_decode import _kernel as _dense_kernel
+from repro.kernels.flash_decode import (
+    _kernel as _dense_kernel,
+    group_major,
+    head_major,
+    scratch_shapes,
+)
 
 
 def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-            l_ref, *, ps, scale):
+            l_ref, *, ps, scale, pin):
     # the accumulator body IS flash_decode's kernel with block_k ==
     # page_size — only the scalar-prefetch ref (unused in the body) and the
     # K/V index maps differ, so the bitwise-equality contract holds by
@@ -45,7 +52,7 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     # their block index is clamped in ``kv_index`` so no fresh fetch
     # happens either.
     _dense_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                  l_ref, bk=ps, scale=scale)
+                  l_ref, bk=ps, scale=scale, pin=pin)
 
 
 def paged_flash_decode(
@@ -60,7 +67,6 @@ def paged_flash_decode(
     B, _, H, hd = q.shape
     _, ps, KV, _ = k_pages.shape
     assert H % KV == 0
-    g = H // KV
     P = tables.shape[1]
     scale = 1.0 / math.sqrt(hd)
     lens = jnp.broadcast_to(
@@ -68,33 +74,31 @@ def paged_flash_decode(
     )
     tables = jnp.asarray(tables, jnp.int32)
 
-    def kv_index(b, h, ki, tbl, lens):
+    def kv_index(b, ki, tbl, lens):
         # walk the page table; clamp blocks past the covered length to the
         # last valid page so the pipeline re-uses the previous fetch
         last = jnp.maximum(lens[b] - 1, 0) // ps
-        return (tbl[b, jnp.minimum(ki, last)], 0, h // g, 0)
+        return (tbl[b, jnp.minimum(ki, last)], 0, 0, 0)
+
+    def q_index(b, ki, tbl, lens):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, P),
+        grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, ki, tbl, lens: (b, 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_index),
-            pl.BlockSpec((1, ps, 1, hd), kv_index),
+            pl.BlockSpec((1, 1, H, hd), q_index),
+            pl.BlockSpec((1, ps, KV, hd), kv_index),
+            pl.BlockSpec((1, ps, KV, hd), kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, hd), lambda b, h, ki, tbl, lens: (b, 0, h, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, H, hd), q_index),
+        scratch_shapes=scratch_shapes(H, KV, hd),
     )
-    kernel = functools.partial(_kernel, ps=ps, scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, ps=ps, scale=scale, pin=interpret)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
         interpret=interpret,
-    )(tables, lens, q, k_pages, v_pages)
+    )(tables, lens, group_major(q, KV), k_pages, v_pages)
+    return head_major(out, KV)

@@ -12,13 +12,13 @@ of the kernel's sequential innermost grid axis.  Each step fetches the
 ``B`` physical pages named by ``tables[:, ki]`` (one dynamic-index gather
 per step — never a dense ``(B, P * page_size)`` copy of the whole window),
 scores them against the query, and folds them into the ``(m, l, acc)``
-carry with *exactly* the accumulator algebra of
-``flash_decode._kernel``: the same f32 casts, the same
-elementwise-multiply + sum-over-``hd`` score, the same ``NEG_INF`` length
-mask, the same ``exp``/rescale order, and the same
-``pl.when(k_start < cur_len)`` skip gate (expressed as a ``where`` select
-on the carry — the gate matters: a fully-masked page would otherwise
-contribute ``exp(NEG_INF - NEG_INF) == 1`` to ``l``).
+carry through ``flash_decode.online_update`` — the very function the
+Pallas kernel body calls, vmapped over the batch — so the f32 casts,
+the score, the ``NEG_INF`` length mask and the ``exp``/rescale order are
+the kernel's by construction.  The kernel's ``pl.when(k_start <
+cur_len)`` skip gate becomes a ``where`` select on the carry (the gate
+matters: a fully-masked page would otherwise contribute
+``exp(NEG_INF - NEG_INF) == 1`` to ``l``).
 
 The loop's trip count is data-dependent: it stops after
 ``ceil(max(cur_len) / page_size)`` columns, because any page at or past
@@ -40,12 +40,18 @@ only reachable through the explicit ``EngineConfig.kv_dtype`` opt-in.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_decode import NEG_INF
+from repro.kernels.flash_decode import (
+    NEG_INF,
+    group_major,
+    head_major,
+    online_update,
+)
 
 
 def paged_flash_decode_xla(
@@ -68,7 +74,11 @@ def paged_flash_decode_xla(
         jnp.asarray(cur_len, jnp.int32).reshape(-1), (B,)
     )
     tables = jnp.asarray(tables, jnp.int32)
-    qf = q[:, 0].astype(jnp.float32)                       # (B, H, hd)
+    qg = group_major(q[:, 0].astype(jnp.float32), KV).reshape(B, g, KV, hd)
+    update = jax.vmap(
+        functools.partial(online_update, scale=scale, pin=True),
+        in_axes=(0, 0, 0, 0, 0, 0, None, 0),
+    )
 
     def step(ki, carry):
         m, l, acc = carry
@@ -78,30 +88,22 @@ def paged_flash_decode_xla(
         if k_scale is not None:
             k = k * k_scale[pids][:, None, None, None]
             v = v * v_scale[pids][:, None, None, None]
-        k = jnp.repeat(k, g, axis=2)                       # (B, ps, H, hd)
-        v = jnp.repeat(v, g, axis=2)
-        s = jnp.sum(k * qf[:, None, :, :], axis=-1) * scale   # (B, ps, H)
-        pos = ki * ps + jax.lax.broadcasted_iota(jnp.int32, (ps,), 0)
-        s = jnp.where(pos[None, :, None] < lens[:, None, None], s, NEG_INF)
-        m_cur = jnp.maximum(m, jnp.max(s, axis=1))         # (B, H)
-        alpha = jnp.exp(m - m_cur)
-        p = jnp.exp(s - m_cur[:, None, :])                 # (B, ps, H)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        acc_new = acc * alpha[..., None] + jnp.sum(p[..., None] * v, axis=1)
-        live = (ki * ps < lens)[:, None]                   # (B, 1)
-        m = jnp.where(live, m_cur, m)
+        pos = ki * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1, 1, 1), 0)
+        m_new, l_new, acc_new = update(qg, k, v, m, l, acc, pos, lens)
+        live = (ki * ps < lens)[:, None, None, None]       # (B, 1, 1, 1)
+        m = jnp.where(live, m_new, m)
         l = jnp.where(live, l_new, l)
-        acc = jnp.where(live[..., None], acc_new, acc)
+        acc = jnp.where(live, acc_new, acc)
         return (m, l, acc)
 
     init = (
-        jnp.full((B, H), NEG_INF, jnp.float32),
-        jnp.zeros((B, H), jnp.float32),
-        jnp.zeros((B, H, hd), jnp.float32),
+        jnp.full((B, g, KV, 1), NEG_INF, jnp.float32),
+        jnp.zeros((B, g, KV, 1), jnp.float32),
+        jnp.zeros((B, g, KV, hd), jnp.float32),
     )
     # stop at the last page any lane still covers — everything past it is
     # fully masked and would leave the carry bit-for-bit unchanged
     n_live = jnp.minimum((jnp.max(lens) + ps - 1) // ps, P).astype(jnp.int32)
     m, l, acc = jax.lax.fori_loop(0, n_live, step, init)
-    denom = jnp.maximum(l, 1e-30)
-    return (acc / denom[..., None]).astype(q.dtype)[:, None]
+    o = (acc / jnp.maximum(l, 1e-30)).reshape(B, H, hd)
+    return head_major(o, KV).astype(q.dtype)[:, None]

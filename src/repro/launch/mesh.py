@@ -10,12 +10,9 @@ import jax
 
 
 def _mk(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,10 +23,16 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over the actual local devices (smoke tests / CPU training)."""
+    """(data, model) mesh over the local devices (CPU training, one chip,
+    or one four-chip host).  Asking for more devices than exist is an
+    error: a mesh that silently shrinks would put every shard on one
+    device."""
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
+        raise ValueError(
+            f"mesh data={data} x model={model} needs {data * model} devices,"
+            f" {n} available"
+        )
     return _mk((data, model), ("data", "model"))
 
 

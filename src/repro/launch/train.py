@@ -48,9 +48,10 @@ from repro.ft.trace import (
     replay_engine,
     verify_replay,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.launch.state import init_state
-from repro.launch.steps import make_train_step
+from repro.launch.state import init_state, state_specs, to_shardings
+from repro.launch.steps import build_rules, make_train_step
 
 _log = logging.getLogger("repro.train")
 
@@ -89,8 +90,13 @@ class Trainer:
         self.seed = seed
 
         key = jax.random.PRNGKey(seed)
-        with self.mesh:
-            self.state = init_state(cfg, train, mecefo, key)
+        # the state starts on the step's shardings: an uncommitted initial
+        # state would compile the step a second time on step 1
+        rules = build_rules(cfg, self.mesh, self.parallel)
+        self.state = jax.device_put(
+            init_state(cfg, train, mecefo, key),
+            to_shardings(self.mesh, state_specs(cfg, train, mecefo, rules)),
+        )
 
         # -- chaos engine: replayed trace > explicit injectors > scenario ---
         self.replay_trace = None
@@ -290,6 +296,10 @@ class Trainer:
                 if self.ckpt and step_idx and step_idx % self.train_cfg.checkpoint_every == 0:
                     self.ckpt.save_async(self.state, step_idx)
 
+                # the host read ends the step: its wall time covers the
+                # device work, not only the dispatch
+                loss = float(metrics["loss"])
+                grad_norm = float(metrics["grad_norm"])
                 dt = time.time() - t0
             self._obs_step_wall.observe(dt)
             self._obs_steps.inc()
@@ -314,8 +324,8 @@ class Trainer:
                 )
             rec = {
                 "step": step_idx,
-                "loss": float(metrics["loss"]),
-                "grad_norm": float(metrics["grad_norm"]),
+                "loss": loss,
+                "grad_norm": grad_norm,
                 "seconds": dt,
                 "failed": len(self.controller.plan.failed),
                 "stragglers": len(slow),
@@ -445,6 +455,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     args = ap.parse_args(argv)
     obs.logging_setup()
+    enable_compile_cache()
 
     trace_mode, trace_path = args.trace or (None, None)
     if trace_mode not in (None, "record", "replay"):

@@ -35,6 +35,7 @@ from repro.ft.injectors import (
     TrafficSpikeInjector,
 )
 from repro.ft.events import FAIL, TRAFFIC_SPIKE, FailureEvent
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_flags, build_rules
 from repro.models.params import init_params
@@ -349,6 +350,7 @@ def main(argv=None) -> int:
                          "render with 'python -m repro.obs incidents PATH'")
     args = ap.parse_args(argv)
     obs.logging_setup()
+    enable_compile_cache()
     if args.ft_policy:
         from repro.ft.policy import parse_policy
         try:

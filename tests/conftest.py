@@ -6,6 +6,11 @@ os.environ.pop("XLA_FLAGS", None)
 
 import jax  # noqa: E402
 
+# The persistent compile cache stays off in tests: entry points that the
+# suite calls (``launch/train.py:main``) would otherwise write to it, and
+# the described-TPU compiles in test_chip_compile.py must stay silent.
+jax.config.update("jax_enable_compilation_cache", False)
+
 import pytest  # noqa: E402
 
 from repro.configs.base import ModelConfig, MoEConfig, SSMConfig  # noqa: E402

@@ -155,12 +155,8 @@ def test_grad_compression_psum():
     """int8-compressed psum ~ exact psum (shard_map path)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     from repro.core.grad_sync import compress_psum
 

@@ -96,6 +96,53 @@ def test_default_accum_reasonable():
     assert default_accum(cfg, SHAPES["decode_32k"], mesh) == 1
 
 
+def test_host_mesh_refuses_more_devices_than_exist():
+    from repro.launch.mesh import make_host_mesh
+
+    n = len(jax.devices())
+    assert make_host_mesh(data=n).devices.size == n
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=n + 1)
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch, tmp_path):
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    assert compile_cache.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv(compile_cache.ENV)
+    repo = Path(__file__).resolve().parents[1]
+    assert compile_cache.compile_cache_dir() == str(repo / ".jax_cache")
+
+
+def test_trainer_starts_on_step_shardings_and_compiles_once():
+    """The initial state is committed to the step's shardings, so step 1
+    reuses step 0's executable instead of compiling it again."""
+    from repro.ft.failures import SCENARIOS
+    from repro.launch.train import Trainer
+    from tests.conftest import TINY_DENSE
+
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    tr = Trainer(
+        TINY_DENSE, ShapeConfig("t", 16, 4, "train"), TrainConfig(steps=3),
+        scenario=SCENARIOS["none"], n_dp=2, n_stages=2,
+    )
+    tr.run(1, log_every=0)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        tr.run(2, log_every=0)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
 def test_trainer_static_mode_compile_cache():
     """Static mode compiles one executable per distinct NDB plan."""
     from repro.ft.failures import SCENARIOS
