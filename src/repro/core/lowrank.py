@@ -37,10 +37,13 @@ Tree = Any
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.jit, static_argnums=1)
 def svd_projection(w: jnp.ndarray, rank: int) -> jnp.ndarray:
     """Top-`rank` input-space singular vectors of a stacked weight.
 
     Accepts (..., n, m); returns (..., n, r). Computed in fp32, cast back.
+    One program per weight shape, named ``jit_svd_projection`` in a
+    profile.
     """
     rank = min(rank, w.shape[-2], w.shape[-1])
     u, _s, _vh = jnp.linalg.svd(w.astype(jnp.float32), full_matrices=False)
@@ -141,6 +144,11 @@ def _replicate(a):
         return a
 
 
+# the named scope of the low-rank Wgrad inside the custom backward rules:
+# a profile's ops under it are the degraded path's extra device work
+LOWRANK_SCOPE = "lowrank_wgrad"
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(4,))
 def lowrank_linear(x, w, v1, keep, mode: str = "exact"):
     """``y = x @ w`` with a MeCeFO backward for dW.
@@ -168,23 +176,26 @@ def _ll_bwd(mode, res, dy):
         dw = xf.T @ dyf
     elif mode in ("degraded", "degraded_sync"):
         # FLOP-efficient order: never materialize the full x^T dy.
-        p = xf @ v1                     # (b, r)
-        a = p.T @ dyf                   # (r, m)
-        if mode == "degraded_sync":
-            # Beyond-paper: force the DP all-reduce onto the factored (r, m)
-            # gradient instead of the (n, m) product — cuts collective bytes
-            # by r/n for degraded layers (see DESIGN.md §3).
-            a = _replicate(a)
-        dw = v1 @ a                     # (n, m)
+        with jax.named_scope(LOWRANK_SCOPE):
+            p = xf @ v1                 # (b, r)
+            a = p.T @ dyf               # (r, m)
+            if mode == "degraded_sync":
+                # Beyond-paper: force the DP all-reduce onto the factored
+                # (r, m) gradient instead of the (n, m) product — cuts
+                # collective bytes by r/n for degraded layers (DESIGN.md §3).
+                a = _replicate(a)
+            dw = v1 @ a                 # (n, m)
     elif mode == "mixed":
         k = keep.astype(dy.dtype)
         k = k.reshape(k.shape + (1,) * (dy.ndim - 1))
         dy_keep = (dy * k).reshape(-1, dy.shape[-1])
-        dy_skip = (dy * (1 - k)).reshape(-1, dy.shape[-1])
         dw_exact = xf.T @ dy_keep
-        p = xf @ v1
-        a = p.T @ dy_skip
-        dw = dw_exact + v1 @ a
+        with jax.named_scope(LOWRANK_SCOPE):
+            dy_skip = (dy * (1 - k)).reshape(-1, dy.shape[-1])
+            p = xf @ v1
+            a = p.T @ dy_skip
+            dw_low = v1 @ a
+        dw = dw_exact + dw_low
     else:
         raise ValueError(mode)
     return dx, dw.astype(w.dtype), jnp.zeros_like(v1), jnp.zeros_like(keep)
@@ -218,17 +229,20 @@ def _llg_bwd(mode, res, dy):
     if mode == "exact":
         dw = jnp.einsum("ecn,ecm->enm", x, dy)
     elif mode in ("degraded", "degraded_sync"):
-        p = jnp.einsum("ecn,enr->ecr", x, v1)
-        a = jnp.einsum("ecr,ecm->erm", p, dy)
-        if mode == "degraded_sync":
-            a = _replicate(a)
-        dw = jnp.einsum("enr,erm->enm", v1, a)
+        with jax.named_scope(LOWRANK_SCOPE):
+            p = jnp.einsum("ecn,enr->ecr", x, v1)
+            a = jnp.einsum("ecr,ecm->erm", p, dy)
+            if mode == "degraded_sync":
+                a = _replicate(a)
+            dw = jnp.einsum("enr,erm->enm", v1, a)
     elif mode == "mixed":
         k = keep.astype(dy.dtype)[..., None]
         dw = jnp.einsum("ecn,ecm->enm", x, dy * k)
-        p = jnp.einsum("ecn,enr->ecr", x, v1)
-        a = jnp.einsum("ecr,ecm->erm", p, dy * (1 - k))
-        dw = dw + jnp.einsum("enr,erm->enm", v1, a)
+        with jax.named_scope(LOWRANK_SCOPE):
+            p = jnp.einsum("ecn,enr->ecr", x, v1)
+            a = jnp.einsum("ecr,ecm->erm", p, dy * (1 - k))
+            dw_low = jnp.einsum("enr,erm->enm", v1, a)
+        dw = dw + dw_low
     else:
         raise ValueError(mode)
     return dx, dw.astype(w.dtype), jnp.zeros_like(v1), jnp.zeros_like(keep)

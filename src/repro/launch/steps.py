@@ -229,19 +229,22 @@ def make_train_step(
             metrics = jax.tree.map(lambda x: x[-1], ms)
             metrics["loss"] = loss
 
-        if mecefo.skip_mha_backward and ndb_mode in ("dynamic", "static"):
-            # eq. (1), with |N_l|/n measured over live examples only: under an
-            # elastic resize the repartitioned batch keeps every weight at 1,
-            # while a transient whole-rank failure zero-weights its slice and
-            # must not deflate the per-layer active fraction.
-            keep_full = ndb["keep"] if ndb_mode == "dynamic" else _static_keep
-            w_full = ndb["example_weight"] if ndb_mode == "dynamic" else _static_w
-            grads = rescale_skipped_grads(grads, keep_full, cfg, w_full)
-        grads, gnorm = clip_by_global_norm(grads, train.grad_clip)
-        lr = schedule(state.step)
-        new_params, new_opt = apply_update(
-            state.params, grads, state.opt, lr, state.step, train
-        )
+        with jax.named_scope("optimizer"):
+            if mecefo.skip_mha_backward and ndb_mode in ("dynamic", "static"):
+                # eq. (1), with |N_l|/n measured over live examples only:
+                # under an elastic resize the repartitioned batch keeps every
+                # weight at 1, while a transient whole-rank failure
+                # zero-weights its slice and must not deflate the per-layer
+                # active fraction.
+                keep_full = ndb["keep"] if ndb_mode == "dynamic" else _static_keep
+                w_full = (ndb["example_weight"] if ndb_mode == "dynamic"
+                          else _static_w)
+                grads = rescale_skipped_grads(grads, keep_full, cfg, w_full)
+            grads, gnorm = clip_by_global_norm(grads, train.grad_clip)
+            lr = schedule(state.step)
+            new_params, new_opt = apply_update(
+                state.params, grads, state.opt, lr, state.step, train
+            )
         new_state = TrainState(
             step=state.step + 1, params=new_params, opt=new_opt, proj=state.proj
         )

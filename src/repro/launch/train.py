@@ -239,6 +239,66 @@ class Trainer:
                 self.controller.record_transfer(receipt)
         self._pending_rejoin = set(self.xfer.pending)
 
+    def _record_step(self, i, step_idx, dt, loss, grad_norm, outcome, slow,
+                     log_every):
+        """A finished step's telemetry, flight frame, history row and logs."""
+        self._obs_step_wall.observe(dt)
+        self._obs_steps.inc()
+        self.controller.observe_step_time(dt)
+        if self.controller.incidents is not None:
+            # one flight-recorder frame per step (wall_s/snap_blocked_s are
+            # unpinned; the rest replay bit-exactly)
+            self.controller.incidents.record_frame(
+                step_idx,
+                wall_s=dt,
+                goodput=self.controller.plan.dp_size(),
+                dp_size=self.controller.plan.dp_size(),
+                failed=len(self.controller.plan.failed),
+                pending=len(self._pending_rejoin),
+                snap_blocked_s=(
+                    self.xfer.telemetry()["snapshot_blocked_s"]
+                    if self.xfer is not None else None
+                ),
+            )
+        rec = {
+            "step": step_idx,
+            "loss": loss,
+            "grad_norm": grad_norm,
+            "seconds": dt,
+            "failed": len(self.controller.plan.failed),
+            "stragglers": len(slow),
+            "net_inflation": outcome.net_inflation,
+            "degraded_frac": self.controller.degraded_layer_fraction(),
+            "dp_size": self.controller.plan.dp_size(),
+            "pending_rejoin": len(self._pending_rejoin),
+        }
+        self.history.append(rec)
+        rp = self.controller.last_reshard
+        if log_every and rp is not None and rp is not self._logged_reshard:
+            self._logged_reshard = rp  # each resize produces a fresh plan
+            measured = ""
+            if self.xfer is not None:
+                acc = self.controller.accounting
+                measured = (
+                    f" measured={acc.measured_transfer_bytes/1e6:.1f}MB"
+                    f" pending={sorted(self._pending_rejoin)}"
+                )
+            _log.info(
+                "step %5d elastic resize: dp %d->%d dropped=%s "
+                "rejoined=%s transfer=%.1fMB (%s)%s",
+                step_idx, len(rp.old_active), rp.dp_size,
+                list(rp.dropped), list(rp.rejoined),
+                rp.transfer_bytes / 1e6, rp.source, measured,
+            )
+        if log_every and i % log_every == 0:
+            _log.info(
+                "step %5d loss %.4f gnorm %.3f %.0fms failed=%d "
+                "slow=%d deg=%.2f dp=%d",
+                rec["step"], rec["loss"], rec["grad_norm"], dt * 1e3,
+                rec["failed"], rec["stragglers"], rec["degraded_frac"],
+                rec["dp_size"],
+            )
+
     # ------------------------------------------------------------------
     def run(self, steps: Optional[int] = None, log_every: int = 10):
         steps = steps or self.train_cfg.steps
@@ -247,33 +307,32 @@ class Trainer:
                 t0 = time.time()
                 step_idx = int(self.state.step)
                 outcome = self.process.step(step_idx)
-                changed, slow = self.controller.apply_chaos(outcome)
+                _, slow = self.controller.apply_chaos(outcome)
                 if (self.controller.policy is not None
                         and self.process.recorder is not None):
                     # pin this step's committed decisions right after its
                     # events — replay re-derives and verifies them
                     for dec in self.controller.policy.drain():
                         self.process.recorder.record_decision(dec)
-                if changed and self.mecefo.mode != "off":
-                    pass  # static mode: next _get_step call compiles/caches
                 if self.xfer is not None:
                     with obs.span("trainer.state_transfers"):
                         self._run_state_transfers(step_idx)
 
-                batch = make_batch(
-                    self.cfg, self.shape, step_idx, source=self.source, seed=self.seed
-                )
+                with obs.span("trainer.feed"):
+                    batch = make_batch(
+                        self.cfg, self.shape, step_idx, source=self.source,
+                        seed=self.seed,
+                    )
                 key = self._step_key()
-                jitted = self._get_step(key)
-                with self.mesh:
-                    if key[0] == "dynamic":
+                args = (self.state, batch)
+                if key[0] == "dynamic":
+                    with obs.span("trainer.masks"):
                         keep, weight = plan_to_masks(
                             self._mask_plan(), self.cfg, self.shape.global_batch
                         )
-                        ndb = {"keep": keep, "example_weight": weight}
-                        self.state, metrics = jitted(self.state, batch, ndb)
-                    else:
-                        self.state, metrics = jitted(self.state, batch)
+                    args += ({"keep": keep, "example_weight": weight},)
+                with obs.span("trainer.dispatch"), self.mesh:
+                    self.state, metrics = self._get_step(key)(*args)
 
                 # technique III: refresh V1 every tau steps (Alg. 3)
                 if (
@@ -281,7 +340,7 @@ class Trainer:
                     and self.mecefo.lowrank_wgrad
                     and step_idx % self.mecefo.svd_period == 0
                 ):
-                    with self.mesh:
+                    with obs.span("lowrank.refresh"), self.mesh:
                         self.state = self.state._replace(
                             proj=refresh_projections(
                                 self.state.params, self.cfg, self.mecefo.rank
@@ -298,68 +357,13 @@ class Trainer:
 
                 # the host read ends the step: its wall time covers the
                 # device work, not only the dispatch
-                loss = float(metrics["loss"])
-                grad_norm = float(metrics["grad_norm"])
+                with obs.span("trainer.read"):
+                    loss = float(metrics["loss"])
+                    grad_norm = float(metrics["grad_norm"])
                 dt = time.time() - t0
-            self._obs_step_wall.observe(dt)
-            self._obs_steps.inc()
-            self.controller.observe_step_time(dt)
-            if self.controller.incidents is not None:
-                # one flight-recorder frame per step (wall_s/span_s/
-                # snap_blocked_s are unpinned; the rest replay bit-exactly)
-                self.controller.incidents.record_frame(
-                    step_idx,
-                    wall_s=dt,
-                    span_s=sum(
-                        t for *_, t in obs.get_tracer().timeline()
-                    ),
-                    goodput=self.controller.plan.dp_size(),
-                    dp_size=self.controller.plan.dp_size(),
-                    failed=len(self.controller.plan.failed),
-                    pending=len(self._pending_rejoin),
-                    snap_blocked_s=(
-                        self.xfer.telemetry()["snapshot_blocked_s"]
-                        if self.xfer is not None else None
-                    ),
-                )
-            rec = {
-                "step": step_idx,
-                "loss": loss,
-                "grad_norm": grad_norm,
-                "seconds": dt,
-                "failed": len(self.controller.plan.failed),
-                "stragglers": len(slow),
-                "net_inflation": outcome.net_inflation,
-                "degraded_frac": self.controller.degraded_layer_fraction(),
-                "dp_size": self.controller.plan.dp_size(),
-                "pending_rejoin": len(self._pending_rejoin),
-            }
-            self.history.append(rec)
-            rp = self.controller.last_reshard
-            if log_every and rp is not None and rp is not self._logged_reshard:
-                self._logged_reshard = rp  # each resize produces a fresh plan
-                measured = ""
-                if self.xfer is not None:
-                    acc = self.controller.accounting
-                    measured = (
-                        f" measured={acc.measured_transfer_bytes/1e6:.1f}MB"
-                        f" pending={sorted(self._pending_rejoin)}"
-                    )
-                _log.info(
-                    "step %5d elastic resize: dp %d->%d dropped=%s "
-                    "rejoined=%s transfer=%.1fMB (%s)%s",
-                    step_idx, len(rp.old_active), rp.dp_size,
-                    list(rp.dropped), list(rp.rejoined),
-                    rp.transfer_bytes / 1e6, rp.source, measured,
-                )
-            if log_every and i % log_every == 0:
-                _log.info(
-                    "step %5d loss %.4f gnorm %.3f %.0fms failed=%d "
-                    "slow=%d deg=%.2f dp=%d",
-                    rec["step"], rec["loss"], rec["grad_norm"], dt * 1e3,
-                    rec["failed"], rec["stragglers"], rec["degraded_frac"],
-                    rec["dp_size"],
-                )
+                with obs.span("trainer.record"):
+                    self._record_step(i, step_idx, dt, loss, grad_norm,
+                                      outcome, slow, log_every)
         if self.ckpt:
             self.ckpt.wait()
         if self.xfer is not None:

@@ -71,13 +71,14 @@ def _apply_block(
     aux = jnp.float32(0)
     if kind == "attn":
         keep_attn = keep_l if ctx.mecefo.skip_mha_backward else 1.0
-        h, new_cache = attention_block(
-            bp["mixer"], h, cfg, rules, keep_attn, positions,
-            cache=cache_l, cur_len=cur_len,
-            attn_chunk=flags.attn_chunk, causal_slice=flags.causal_slice,
-            history=prefill_history, page_tables=page_tables,
-            page_size=page_size, kernel_impl=kernel_impl,
-        )
+        with jax.named_scope("attn"):
+            h, new_cache = attention_block(
+                bp["mixer"], h, cfg, rules, keep_attn, positions,
+                cache=cache_l, cur_len=cur_len,
+                attn_chunk=flags.attn_chunk, causal_slice=flags.causal_slice,
+                history=prefill_history, page_tables=page_tables,
+                page_size=page_size, kernel_impl=kernel_impl,
+            )
     else:
         h, new_cache = ssm_block(
             bp["mixer"], h, cfg, rules,
@@ -85,18 +86,19 @@ def _apply_block(
             keep=keep_l, lowrank_mode=lowrank_mode,
             recompute=ctx.recompute_ffn(), cache=cache_l,
         )
-    if is_moe:
-        h, aux = moe_block(
-            bp["ffn"], h, cfg, rules, n_dp_shards=flags.n_dp_shards,
-            proj=None if pj is None else pj.get("ffn"),
-            keep=keep_l, lowrank_mode=lowrank_mode, recompute=recompute,
-        )
-    else:
-        h = ffn_block(
-            bp["ffn"], h, cfg, rules,
-            proj=None if pj is None else pj.get("ffn"),
-            keep=keep_l, lowrank_mode=lowrank_mode, recompute=recompute,
-        )
+    with jax.named_scope("ffn"):
+        if is_moe:
+            h, aux = moe_block(
+                bp["ffn"], h, cfg, rules, n_dp_shards=flags.n_dp_shards,
+                proj=None if pj is None else pj.get("ffn"),
+                keep=keep_l, lowrank_mode=lowrank_mode, recompute=recompute,
+            )
+        else:
+            h = ffn_block(
+                bp["ffn"], h, cfg, rules,
+                proj=None if pj is None else pj.get("ffn"),
+                keep=keep_l, lowrank_mode=lowrank_mode, recompute=recompute,
+            )
     return h, new_cache, aux
 
 
@@ -226,8 +228,14 @@ def forward_loss(
     ctx: NDBContext,
     flags: ExecFlags,
 ):
-    """Training loss (+ metrics dict)."""
-    h, token_w = frontends.embed_inputs(params, batch, cfg)
+    """Training loss (+ metrics dict).
+
+    The step's device work carries named scopes (HLO ``op_name`` metadata,
+    so a profile can split the step): ``embed``, ``attn`` and ``ffn`` (per
+    layer, see ``_apply_block``), ``head``.
+    """
+    with jax.named_scope("embed"):
+        h, token_w = frontends.embed_inputs(params, batch, cfg)
     h = constrain(h, rules, "batch", "seq", None)
     labels = frontends.full_labels(batch, cfg)
     S = h.shape[1]
@@ -239,11 +247,12 @@ def forward_loss(
     h, _, aux = run_trunk(
         params, proj, h, cfg, rules, ctx, flags, positions=positions
     )
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    ce = chunked_cross_entropy(
-        h, _unembed(params), labels, token_w, rules, chunk=flags.ce_chunk,
-        vocab_size=cfg.vocab_size,
-    )
+    with jax.named_scope("head"):
+        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        ce = chunked_cross_entropy(
+            h, _unembed(params), labels, token_w, rules, chunk=flags.ce_chunk,
+            vocab_size=cfg.vocab_size,
+        )
     loss = ce + aux
     return loss, {"loss": loss, "ce": ce, "aux": aux}
 

@@ -266,6 +266,12 @@ def declared_names() -> Tuple[str, ...]:
 # docs/observability.md documents exactly this set (pinned by test_docs).
 SPANS: Tuple[str, ...] = (
     "trainer.step",              # one optimizer step (chaos -> jitted step)
+    "trainer.feed",              # making the step's batch on the host
+    "trainer.masks",             # NDB plan -> keep / example-weight masks
+    "trainer.dispatch",          # the jitted step's call (async dispatch)
+    "lowrank.refresh",           # dispatching the SVD projection refresh
+    "trainer.read",              # blocking read of the step's loss
+    "trainer.record",            # frame, history and log bookkeeping
     "trainer.state_transfers",   # executing queued restore transfers
     "controller.apply_chaos",    # failure outcome -> NDB plan + accounting
     "snapshot.capture",          # blocking capture into the back buffer

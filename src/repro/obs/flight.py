@@ -2,11 +2,11 @@
 
 Every run loop (trainer step, router step) records one *frame* per step:
 the step index plus a small dict of sampled quantities — step wall time,
-tokens emitted, DP size, queue depth, free KV pages, the tracer's
-accumulated span wall.  The ring keeps the last ``capacity`` frames; when
-an incident opens, :mod:`repro.obs.incidents` copies the pre/post window
-around the opening step out of the ring into the incident record, like a
-crashed aircraft's last N seconds of instruments.
+tokens emitted, DP size, queue depth, free KV pages.  The ring keeps the
+last ``capacity`` frames; when an incident opens,
+:mod:`repro.obs.incidents` copies the pre/post window around the opening
+step out of the ring into the incident record, like a crashed aircraft's
+last N seconds of instruments.
 
 Determinism contract: the ring is a pure function of the ``record()``
 calls — no clocks, no sampling jitter.  Frame *fields* split into two
@@ -15,9 +15,10 @@ classes (see docs/observability.md):
 * **pinned** — derived from replay-pinned quantities (step index, token
   counts, dp_size, queue depth, free pages).  These replay bit-exactly
   and may appear in golden incident logs.
-* **unpinned** — wall-clock quantities (``wall_s``, ``span_s``).  They
-  ride along in the JSONL for humans and the cost model but are dropped
-  from the pinned projection a golden log is verified against.
+* **unpinned** — wall-clock quantities (``wall_s``, ``snap_blocked_s``;
+  ``span_s`` in logs from before frames dropped it).  They ride along in
+  the JSONL for humans and the cost model but are dropped from the pinned
+  projection a golden log is verified against.
 
 The recorder is a pure side channel: it only ever *reads* run state.
 """
@@ -27,7 +28,8 @@ from collections import deque
 from typing import Deque, Dict, List, Optional
 
 # frame fields that are NOT derived from replay-pinned quantities; the
-# pinned projection (and therefore golden incident logs) drops these
+# pinned projection (and therefore golden incident logs) drops these.
+# ``span_s`` is no longer recorded but older logs hold it
 UNPINNED_FRAME_FIELDS = ("wall_s", "span_s", "snap_blocked_s")
 
 DEFAULT_CAPACITY = 64

@@ -7,6 +7,12 @@ apply_chaos"``), which *is* the nested timeline — the report renders the
 tree straight from these paths, and memory stays bounded by the number
 of distinct paths, not the number of spans.
 
+While it records, a span also opens a ``jax.profiler.TraceAnnotation``
+of the same name, so a profiler trace taken over the run holds every span
+as a host event on the device trace's clock; with no trace running the
+annotation costs about a microsecond.  A tracer with recording off opens
+nothing (and imports no jax).
+
 Spans are a pure side channel: disabling them (``configure(enabled=
 False)``) changes nothing but the export, and enabling them must never
 perturb a golden-trace replay (pinned by tests/test_obs_neutrality.py).
@@ -47,19 +53,22 @@ class Tracer:
             raise KeyError(
                 f"span {name!r} is not declared in repro.obs.catalog.SPANS"
             )
+        from jax.profiler import TraceAnnotation
+
         stack = self._stack()
         stack.append(name)
         path = "/".join(stack)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            stack.pop()
-            with self._lock:
-                agg = self.aggregates.setdefault(path, [0, 0.0])
-                agg[0] += 1
-                agg[1] += dur
+        with TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dur = time.perf_counter() - t0
+                stack.pop()
+                with self._lock:
+                    agg = self.aggregates.setdefault(path, [0, 0.0])
+                    agg[0] += 1
+                    agg[1] += dur
 
     def reset(self) -> None:
         with self._lock:

@@ -524,13 +524,11 @@ class ReplicaSet:
                         pending.discard(ev.req)
                 dt = time.perf_counter() - t0
                 step_wall.append(dt)
-            # one flight-recorder frame per router step (wall_s/span_s are
+            # one flight-recorder frame per router step (wall_s is
             # unpinned; token/queue/page counts replay bit-exactly)
             toks = sum(1 for ev in evs if ev.kind == "token")
             self.incidents.record_frame(
-                t, wall_s=dt,
-                span_s=sum(s for *_, s in obs.get_tracer().timeline()),
-                tokens=toks, goodput=toks,
+                t, wall_s=dt, tokens=toks, goodput=toks,
                 queue_depth=len(self.queue),
                 free_pages=sum(
                     self.engines[r].alloc.free_count

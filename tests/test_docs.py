@@ -28,7 +28,8 @@ _METRIC_TOKEN = re.compile(
     r"^(?:ft|statexfer|serve|train|kernels|incidents)\.[a-z0-9_.]+$"
 )
 _SPAN_TOKEN = re.compile(
-    r"^(?:trainer|controller|snapshot|reshard|engine|router|kernel)\.[a-z0-9_]+$"
+    r"^(?:trainer|controller|snapshot|reshard|engine|router|kernel|lowrank)"
+    r"\.[a-z0-9_]+$"
 )
 
 

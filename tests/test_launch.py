@@ -143,6 +143,47 @@ def test_trainer_starts_on_step_shardings_and_compiles_once():
     assert compiles == []
 
 
+def test_trainer_spans_cover_every_host_phase():
+    """Each host phase of a training iteration is a named span under
+    ``trainer.step``; the existing span paths keep their counts, and the
+    flight-recorder frames no longer carry the tracer's ``span_s``."""
+    from repro import obs
+    from repro.ft.failures import SCENARIOS
+    from repro.launch.train import Trainer
+    from tests.conftest import TINY_DENSE
+
+    tr = Trainer(
+        TINY_DENSE, ShapeConfig("t", 16, 4, "train"), TrainConfig(steps=3),
+        mecefo=MeCeFOConfig(mode="dynamic", rank=8, svd_period=2),
+        scenario=SCENARIOS["none"], n_dp=2, n_stages=2,
+    )
+    tr.process.inject(0, (0, 1), down_steps=10)
+
+    def totals():
+        return {p: c for p, c, _ in obs.get_tracer().timeline()}
+
+    before = totals()
+    tr.run(3, log_every=0)
+    after = totals()
+    got = {p: after[p] - before.get(p, 0) for p in after
+           if after[p] - before.get(p, 0)}
+    assert got == {
+        "trainer.step": 3,
+        "trainer.step/controller.apply_chaos": 3,
+        "trainer.step/trainer.feed": 3,
+        "trainer.step/trainer.masks": 3,
+        "trainer.step/trainer.dispatch": 3,
+        "trainer.step/lowrank.refresh": 2,   # steps 0 and 2
+        "trainer.step/trainer.read": 3,
+        "trainer.step/trainer.record": 3,
+    }
+    assert [h["step"] for h in tr.history] == [0, 1, 2]
+    assert all(h["seconds"] > 0 for h in tr.history)
+    frames = tr.controller.incidents.mgr.flight.frames()
+    assert len(frames) == 3
+    assert all("span_s" not in f and "wall_s" in f for f in frames)
+
+
 def test_trainer_static_mode_compile_cache():
     """Static mode compiles one executable per distinct NDB plan."""
     from repro.ft.failures import SCENARIOS
