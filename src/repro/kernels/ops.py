@@ -16,6 +16,11 @@ fallback* is now explicit, backend-derived, and logged once per wrapper:
   Pallas kernel).  :func:`resolve_paged_impl` picks ``"pallas"`` on TPU,
   ``"xla"`` elsewhere, and ``"pallas-interpret"`` only when interpret
   mode is explicitly requested.
+* Training attention has two paths: the Pallas flash kernel with its
+  backward, or the jnp einsum path of ``models/layers.causal_attention``.
+  :func:`resolve_attention_impl` picks ``"flash"`` where the kernel
+  applies (TPU, one device, tuned blocks dividing the sequence, lane-wide
+  heads, no KV cache) and ``"jnp"`` everywhere else.
 * Block sizes come from the per-backend :class:`KernelTuning` table and
   can be overridden with :func:`configure`.
 """
@@ -71,10 +76,12 @@ class KernelTuning:
 
 
 # The autotuning table: one entry per backend.  TPU keeps the larger MXU/
-# VPU-aligned blocks; the CPU runs the dense kernels in interpret mode, so
-# its block sizes matter mostly for tests.
+# VPU-aligned blocks (attention's from a sweep on a v5e chip, PERF.md); the
+# CPU runs the dense kernels in interpret mode, so its block sizes matter
+# mostly for tests.
 _BACKEND_TUNING = {
-    "tpu": KernelTuning(interpret=False, paged_impl="pallas"),
+    "tpu": KernelTuning(interpret=False, paged_impl="pallas",
+                        attn_block_q=1024, attn_block_k=512),
     "cpu": KernelTuning(),
 }
 _tuning_override: Optional[KernelTuning] = None
@@ -129,6 +136,40 @@ def resolve_paged_impl(interpret: Optional[bool] = None,
     return "pallas" if backend == "tpu" else "xla"
 
 
+# The flash backward keeps the whole q/dO sequence of one (batch, KV head)
+# in VMEM, double-buffered, with dQ's f32 accumulator and output block.
+ATTN_RESIDENT_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def resolve_attention_impl(q_shape, n_kv_heads: int, *, cached: bool = False,
+                           backend: Optional[str] = None,
+                           n_devices: Optional[int] = None) -> str:
+    """``"flash"`` where causal attention of queries ``q_shape`` (B, S, H,
+    hd) can take the Pallas flash kernel, else ``"jnp"``.
+
+    The kernel takes it on TPU, without a KV cache (training; prefill
+    keeps the jnp path), on one device (a trace cannot see whether its
+    inputs are sharded, and GSPMD partitions the jnp path but not a
+    kernel), with ``head_dim`` a multiple of 128 lanes, the tuned grid
+    block (shrunk to S) dividing S and its tile a multiple of 128 dividing
+    the block, and the backward's resident sequence within VMEM.
+    """
+    backend = backend or jax.default_backend()
+    n_devices = jax.device_count() if n_devices is None else n_devices
+    _, S, H, hd = q_shape
+    t = get_tuning(backend)
+    bq = min(t.attn_block_q, S)
+    tile = min(t.attn_block_k, bq)
+    resident = 16 * S * (H // n_kv_heads) * hd
+    ok = (
+        backend == "tpu" and not cached and n_devices == 1
+        and hd % 128 == 0 and H % n_kv_heads == 0
+        and tile % 128 == 0 and bq % tile == 0 and S % bq == 0
+        and resident <= ATTN_RESIDENT_VMEM_BYTES
+    )
+    return "flash" if ok else "jnp"
+
+
 _logged: set = set()
 _impl_counters: dict = {}
 
@@ -153,6 +194,16 @@ def _log_choice(name: str, impl: str) -> None:
                 "kernel %s -> %s (backend=%s)",
                 name, impl, jax.default_backend(),
             )
+
+
+def count_attention_sites(impl: str, sites: int = 1) -> None:
+    """Record, at trace time, that ``sites`` attention layers compiled onto
+    ``impl`` (``kernels.attention_sites{impl}``; a scanned layer stack is
+    one traced site standing for each of its layers)."""
+    from repro import obs
+
+    _log_choice("attention", impl)
+    obs.counter("kernels.attention_sites", labels={"impl": impl}).inc(sites)
 
 
 def _pad_to(x, axis: int, multiple: int):
@@ -329,4 +380,5 @@ __all__ = [
     "lowrank_wgrad", "swiglu", "rmsnorm", "ref",
     "KernelTuning", "get_tuning", "configure",
     "default_interpret", "resolve_interpret", "resolve_paged_impl",
+    "resolve_attention_impl", "count_attention_sites",
 ]

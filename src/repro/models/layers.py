@@ -60,15 +60,29 @@ def rope(x, positions, theta: float):
 # ---------------------------------------------------------------------------
 
 
-def causal_attention(q, k, v, *, chunk: int = 1024, causal_slice: bool = False):
-    """Chunked causal attention, jnp reference path (Pallas kernel mirrors it).
+def causal_attention(q, k, v, *, chunk: int = 1024, causal_slice: bool = False,
+                     cached: bool = False, sites: int = 1):
+    """Causal GQA attention. q: (B, S, H, hd); k, v: (B, S, KV, hd).
+    Returns (B, S, H, hd).
 
-    q: (B, S, H, hd); k, v: (B, S, KV, hd). Returns (B, S, H, hd).
-
+    Where ``kernel_ops.resolve_attention_impl`` says ``"flash"`` (on TPU,
+    on one device, not a prefill into a KV cache (``cached``), head_dim a
+    multiple of 128 and S a multiple of the tuned blocks) this is the
+    Pallas flash kernel with its backward (kernels/flash_attention.py):
+    nothing S²-shaped reaches HBM, and the forward's residuals (o and the
+    row logsumexp) replace recomputing it.  Otherwise it is the chunked
+    jnp path below, each chunk's (Qc, S) probabilities recomputed in the
+    backward; ``chunk`` and ``causal_slice`` apply to it alone.
     ``causal_slice=True`` unrolls the query-chunk loop in Python and slices
     K/V to the causal prefix per chunk — halves attention FLOPs at the cost
-    of per-chunk specialization (hillclimb lever; see EXPERIMENTS.md §Perf).
+    of per-chunk specialization.  The choice is counted at trace time in
+    ``kernels.attention_sites{impl}``, ``sites`` layers per call.
     """
+    impl = kernel_ops.resolve_attention_impl(q.shape, k.shape[2],
+                                             cached=cached)
+    kernel_ops.count_attention_sites(impl, sites)
+    if impl == "flash":
+        return kernel_ops.flash_attention(q, k, v)
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -94,7 +108,7 @@ def causal_attention(q, k, v, *, chunk: int = 1024, causal_slice: bool = False):
             return jnp.einsum("bkgqs,bskh->bqkgh", p, v_ctx)
 
     # never keep a chunk's (Qc, S) probabilities for backward — recompute
-    # (the Pallas flash kernel does the same on TPU)
+    # (the flash path recomputes its score tiles in VMEM instead)
     attend = jax.checkpoint(
         attend,
         policy=jax.checkpoint_policies.nothing_saveable,
@@ -172,6 +186,12 @@ def history_attention(q, k_cache, v_cache, off):
     return out.reshape(B, C, H, hd)
 
 
+# The backward recomputes the head merge (a reshape) instead of keeping its
+# output, so it holds one copy of o, which the flash kernel's backward
+# shares as its residual.
+_out_proj = jax.checkpoint(lambda o, wo: o.reshape(*o.shape[:2], -1) @ wo)
+
+
 def attention_block(
     p,
     x,
@@ -188,12 +208,15 @@ def attention_block(
     page_tables=None,
     page_size: Optional[int] = None,
     kernel_impl: Optional[str] = None,
+    sites: int = 1,
 ):
     """Pre-norm MHA sublayer with residual; returns (y, new_cache).
 
     ``keep`` is the technique-I mask ((B,) array, scalar, or python float).
     The whole MHA branch (incl. its norm) sits behind ``grad_gate`` so
     degraded examples propagate gradients via the residual only.
+    ``sites`` is the number of layers this call stands for (a scanned
+    stack's length), for the attention-site count.
     """
     xn = rmsnorm(x, p["ln"], cfg.norm_eps)
     B, S, _ = x.shape
@@ -287,12 +310,14 @@ def attention_block(
                 ),
             }
             o = causal_attention(
-                q, k, v, chunk=attn_chunk, causal_slice=causal_slice
+                q, k, v, chunk=attn_chunk, causal_slice=causal_slice,
+                cached=True, sites=sites,
             )
     else:
-        o = causal_attention(q, k, v, chunk=attn_chunk, causal_slice=causal_slice)
+        o = causal_attention(q, k, v, chunk=attn_chunk,
+                             causal_slice=causal_slice, sites=sites)
 
-    y = o.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
+    y = _out_proj(o, p["wo"])
     # technique I: skip MHA in backward for degraded examples. A static 0
     # becomes stop_gradient so XLA provably DCEs the whole MHA backward
     # (Wgrad + Dgrad + saved residuals) — the paper's memory/compute claim.

@@ -64,6 +64,7 @@ def _apply_block(
     page_tables=None,
     page_size=None,
     kernel_impl: Optional[str] = None,
+    sites: int = 1,
 ):
     kind, is_moe = pos_kind
     lowrank_mode = ctx.lowrank_mode()
@@ -77,7 +78,7 @@ def _apply_block(
                 cache=cache_l, cur_len=cur_len,
                 attn_chunk=flags.attn_chunk, causal_slice=flags.causal_slice,
                 history=prefill_history, page_tables=page_tables,
-                page_size=page_size, kernel_impl=kernel_impl,
+                page_size=page_size, kernel_impl=kernel_impl, sites=sites,
             )
     else:
         h, new_cache = ssm_block(
@@ -138,6 +139,7 @@ def run_trunk(
 
     layer_params = params["layers"]
     layer_proj = proj["layers"] if proj is not None else None
+    scanned = flags.scan_layers and n_periods > 1
 
     def super_block(h, xs):
         bps, pjs, keeps, cls = xs
@@ -159,6 +161,7 @@ def run_trunk(
                 cfg, rules, ctx, flags, positions, cur_len,
                 prefill_history=prefill_history, page_tables=page_tables,
                 page_size=page_size, kernel_impl=kernel_impl,
+                sites=n_periods if scanned else 1,
             )
             aux_tot = aux_tot + aux
             if new_cls is not None:
@@ -167,7 +170,7 @@ def run_trunk(
 
     xs = (layer_params, layer_proj, keep, caches)
 
-    if flags.scan_layers and n_periods > 1:
+    if scanned:
         body = super_block
         if flags.remat == "full":
             body = jax.checkpoint(

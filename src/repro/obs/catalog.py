@@ -213,6 +213,9 @@ def _specs() -> Tuple[MetricSpec, ...]:
         MetricSpec("kernels.impl_calls", COUNTER,
                    "kernel dispatches by resolved implementation",
                    labels=("kernel", "impl")),
+        MetricSpec("kernels.attention_sites", COUNTER,
+                   "causal attention layers traced onto each path (flash "
+                   "kernel or jnp)", labels=("impl",)),
         MetricSpec("incidents.opened", COUNTER,
                    "incidents opened, by event kind", labels=("kind",)),
         MetricSpec("incidents.closed", COUNTER,

@@ -28,7 +28,9 @@ from repro.configs.base import (
     TrainConfig,
     get_config,
 )
+from repro.kernels import flash_attention as fa
 from repro.kernels import flash_decode as fd
+from repro.kernels import ops
 from repro.kernels import paged_decode as pd
 from repro.launch.specs import input_specs, ndb_specs
 from repro.launch.state import state_structs
@@ -91,6 +93,26 @@ def test_dense_flash_decode_kernel_compiles_for_v5e(one_chip):
         _struct((B,), jnp.int32, one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip):
+    """Training attention at the qwen3-0.6b cell's shape (4 x 1024, 16
+    heads over 8 KV heads, hd 128) with the TPU's tuned blocks: the
+    forward and the fused backward kernel."""
+    cfg = get_config("qwen3-0.6b")
+    B, S = 4, 1024
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    t = ops.get_tuning("tpu")
+    q = _struct((B, S, H, hd), jnp.bfloat16, one_chip)
+    kv = _struct((B, S, KV, hd), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, block_q=t.attn_block_q, block_k=t.attn_block_k), q, k, v)
+        return o, vjp(do)
+
+    text = jax.jit(fwd_bwd).lower(q, kv, kv, q).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
 
 
 def _compile_train_step(devices, data):

@@ -118,6 +118,30 @@ def test_flash_attention_compiled_matches_interpret():
                                                         interpret=True))
 
 
+@pytest.mark.parametrize("G", [1, 2])
+def test_flash_attention_vjp_compiled_matches_interpret(G):
+    """Training attention's forward and backward kernels, compiled, against
+    the same kernels in interpret mode (bf16, as in training)."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (2, 256, 2 * G, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, 256, 2, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, 256, 2, 128), jnp.bfloat16)
+    do = jax.random.normal(ks[3], q.shape, jnp.bfloat16)
+
+    def run(interpret):
+        o, vjp = jax.vjp(
+            lambda q, k, v: ops.flash_attention(
+                q, k, v, block_q=128, block_k=128, interpret=interpret),
+            q, k, v)
+        return (o, *vjp(do))
+
+    compiled = _compiled_or_skip(run, False)
+    for got, want in zip(compiled, run(True)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+
 def test_flash_decode_compiled_matches_interpret():
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (2, 1, 4, 32))
