@@ -5,6 +5,39 @@ configuration as it is run) and ``traffic/<traffic>.json`` (the traffic mix
 and the limits of the check).  The traffic's ``kind`` names the driver,
 ``drivers/<kind>.py``.  A per-layer metric ``<name>`` is read by
 ``metrics/<name>.py``.  New cells, mixes and metrics are new files.
+
+A configuration file states the program's model field by field: its
+``repro_config`` names the program's registry entry, and each entry
+``field: file key`` of its ``harness_keys`` sets that field of the
+program's ``ModelConfig`` to the file's value.  A dotted field reaches
+into a sub-config (``moe.n_experts``, ``moe.top_k``, ``moe.d_ff_expert``,
+``moe.every``, ``moe.offset``, ``ssm.d_state``, ``ssm.expand``,
+``ssm.head_dim``, ``ssm.chunk``, ...); top-level fields such as ``family``,
+``attn_every``, ``attn_offset`` and ``ffn_act`` are set the same way.  The
+same map, keyed by field, is the sizes map ``m`` that ``flops.py``, the
+metric readers and the reference read.
+
+The file's ``reference`` names the plain reference, ``<reference>.py`` in
+this directory (``refmodel`` where the file names none).  A reference
+imports nothing of the program and gives:
+
+* ``accept(m)``: raises ``ValueError`` for sizes it does not compute; the
+  drivers call it at set-up, so such a file stops before the window;
+* ``init_weights(m, seed)``: ``{leaf name: array}`` in the configuration's
+  dtype, the leaf names those of ``program.named_leaves`` of the program's
+  parameters (``layers/0/ffn/w_up``);
+* ``run_training(m, job, seed, batches, keep, weight, quant=None)``: the
+  first ``len(batches)`` steps, as ``{"losses": [...], "grad0": {leaf:
+  norm}, "delta": {leaf: norm}}``; ``keep`` (layers, batch) or ``None``,
+  ``weight`` (batch,);
+* ``make_seq_logits(m, quant=None)``: a jitted ``(weights, tokens (1, T),
+  start, n) -> (n, vocab)`` of float32 logits.
+
+``quant`` asks for the control, one precision below the configuration's.
+A new configuration is then added by files alone: its configuration file,
+a traffic file, its reference where ``refmodel`` does not compute it, and
+readers for any new metric; its cell is appended to ``workloads`` in
+``BENCHMARK.json`` and to the ``workloads`` lists of the metrics it reports.
 """
 from __future__ import annotations
 
@@ -68,6 +101,14 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reference_for(config: dict):
+    """The plain reference the configuration file names, once it has
+    accepted the file's sizes."""
+    ref = load_module(BENCH / f"{config.get('reference', 'refmodel')}.py")
+    ref.accept(model_sizes(config))
+    return ref
 
 
 def driver_for(cell: Cell, bench: Optional[Path] = None):

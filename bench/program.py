@@ -4,27 +4,71 @@ helpers.  Nothing here is used by the references."""
 from __future__ import annotations
 
 import dataclasses
+import typing
 
 import jax
 import jax.numpy as jnp
 
+from bench import flops
+
 
 def model_config(config: dict, m: dict):
     """The program's ``ModelConfig`` for a configuration file: the registry
-    entry the file names, with every size set from the file."""
+    entry the file names, with every entry of ``m`` (the file's
+    ``harness_keys``, as ``bench.cell.model_sizes`` reads them) set on it.
+
+    A dotted name (``moe.n_experts``, ``ssm.d_state``) sets a field of that
+    sub-config, which is made from its defaults where the entry has none.  A
+    name that is no field of the program's config raises.  The layer pattern
+    that ``bench.flops`` reads from ``m`` has to be the one the program
+    runs, so that a file cannot leave its family or experts to the registry
+    and have them counted as dense, and the map states every size that
+    count reads."""
     from repro.configs.base import get_config
 
     base = get_config(config["repro_config"])
-    cfg = dataclasses.replace(
-        base, n_layers=m["n_layers"], d_model=m["d_model"],
-        n_heads=m["n_heads"], n_kv_heads=m["n_kv_heads"],
-        head_dim=m["head_dim"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-        norm_eps=m["norm_eps"], rope_theta=m["rope_theta"],
-        qk_norm=m["qk_norm"], tie_embeddings=m["tie_embeddings"],
-        dtype=m["dtype"])
-    if cfg.family != "dense" or cfg.ffn_act != "swiglu" or cfg.moe or cfg.ssm:
-        raise ValueError(f"{cfg.name}: the reference covers dense SwiGLU only")
+    top, subs = {}, {}
+    for key, value in m.items():
+        group, _, name = key.partition(".")
+        if name:
+            subs.setdefault(group, {})[name] = value
+        else:
+            top[key] = value
+    for group, fields in subs.items():
+        top[group] = _with_fields(_sub_config(base, group), fields, group + ".")
+    cfg = _with_fields(base, top, "")
+    runs = [(cfg.layer_kind(i), cfg.layer_is_moe(i))
+            for i in range(cfg.n_layers)]
+    described = flops.layer_pattern(m)
+    if described != runs:
+        raise ValueError(
+            f"{config['name']}: the program runs the layers {runs}, the "
+            f"harness_keys describe {described}; state family, attn_every, "
+            "attn_offset and the moe.* sizes in the file")
+    missing = flops.missing_sizes(m)
+    if missing:
+        raise ValueError(f"{config['name']}: the FLOP count reads {missing}, "
+                         "which the harness_keys leave to the defaults")
     return cfg
+
+
+def _sub_config(cfg, group: str):
+    """The sub-config in field ``group``, or its defaults where it is unset."""
+    hint = typing.get_type_hints(type(cfg)).get(group)
+    kinds = [t for t in typing.get_args(hint) if dataclasses.is_dataclass(t)]
+    if not kinds:
+        raise ValueError(f"harness_keys: {group!r} is no sub-config of the "
+                         f"program's {type(cfg).__name__}")
+    return getattr(cfg, group) or kinds[0]()
+
+
+def _with_fields(obj, fields: dict, prefix: str):
+    names = {f.name for f in dataclasses.fields(obj)}
+    for key in fields:
+        if key not in names:
+            raise ValueError(f"harness_keys: {prefix + key!r} is no field of "
+                             f"the program's {type(obj).__name__}")
+    return dataclasses.replace(obj, **fields)
 
 
 def named_leaves(tree) -> dict:

@@ -45,6 +45,17 @@ HIGHEST = jax.lax.Precision.HIGHEST
 NORMS = ("ln", "final_norm", "q_norm", "k_norm")
 
 
+def accept(m: dict) -> None:
+    """Raises for sizes this reference does not compute: experts, SSD
+    layers, a family other than dense, an activation other than SwiGLU."""
+    other = [k for k in sorted(m) if k.startswith(("moe.", "ssm."))]
+    other += [k for k, ours in (("family", "dense"), ("ffn_act", "swiglu"))
+              if m.get(k, ours) != ours]
+    if other:
+        raise ValueError("the reference covers dense SwiGLU only, not "
+                         + ", ".join(f"{k}={m[k]!r}" for k in other))
+
+
 # ---------------------------------------------------------------------------
 # weights
 # ---------------------------------------------------------------------------

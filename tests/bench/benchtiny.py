@@ -36,6 +36,44 @@ def tiny_config(**over) -> dict:
     return c
 
 
+# Sizes maps of the program's non-dense registry entries at their
+# ``reduced()`` sizes, every size under its program field name: experts on
+# every layer, and Jamba's hybrid cut to one period of 8 layers (one
+# attention layer, seven SSD layers, experts on every second).
+TINY_ARCHS = {
+    "moe": ("qwen3-moe-30b-a3b", {
+        "family": "moe", "n_layers": 2, "d_model": 128, "n_heads": 4,
+        "n_kv_heads": 4, "head_dim": 32, "d_ff": 256, "vocab_size": 512,
+        "norm_eps": 1e-5, "rope_theta": 1e6, "qk_norm": True,
+        "tie_embeddings": False, "dtype": "bfloat16", "moe.n_experts": 8,
+        "moe.top_k": 2, "moe.d_ff_expert": 64}),
+    "hybrid": ("jamba-1.5-large-398b", {
+        "family": "hybrid", "attn_every": 8, "attn_offset": 0,
+        "n_layers": 8, "d_model": 128, "n_heads": 4, "n_kv_heads": 4,
+        "head_dim": 32, "d_ff": 256, "vocab_size": 512, "norm_eps": 1e-5,
+        "rope_theta": 10000.0, "qk_norm": False, "tie_embeddings": False,
+        "dtype": "bfloat16", "moe.n_experts": 8, "moe.top_k": 2,
+        "moe.d_ff_expert": 64, "moe.every": 2, "moe.offset": 1,
+        "ssm.d_state": 16, "ssm.expand": 2, "ssm.head_dim": 16,
+        "ssm.chunk": 32}),
+}
+
+# what tests/bench/recording_reference.py was called with, and the leaf
+# names it answers for
+REFERENCE_CALLS: list = []
+REFERENCE_LEAVES: list = []
+
+
+def arch_config(arch: str, **over) -> dict:
+    """A configuration file for ``TINY_ARCHS[arch]`` and the tiny job."""
+    repro, sizes = TINY_ARCHS[arch]
+    c = tiny_config(name=f"tiny-{arch}", repro_config=repro,
+                    harness_keys={k: k for k in sizes})
+    c.update(sizes)
+    c.update(over)
+    return c
+
+
 def train_traffic(name="train.failover", **over) -> dict:
     t = cells.load_json(ROOT / f"bench/traffic/{name}.json")
     if t["failures"]:

@@ -129,6 +129,112 @@ def test_flop_and_byte_counts_match_hand_counts():
         2 * 393_216 + 4 * 2 * 4 * 32 * 6)
 
 
+def test_the_dense_cell_counts_what_it_counted_before():
+    """The qwen3-0.6b file's figures, as the parent of the per-kind count
+    gave them."""
+    m = cells.model_sizes(cells.load_json(ROOT / "bench/configs/qwen3-0.6b.json"))
+    assert flops.train_flops_per_token(m, 1024) == 3_928_227_840
+    assert flops.matmul_params(m) == 595_984_384
+    assert flops.weight_bytes(m) == 1_192_099_840
+    assert flops.kv_bytes_per_token(m) == 114_688
+    assert flops.decode_round_flops(m, [5, 100]) == 2_408_480_768
+    assert flops.decode_round_bytes(m, [5, 100], 64) == 1_214_119_936
+    assert flops.paged_kernel_bytes(m, [5, 100], 64) == 22_478_848
+
+
+# hand-made maps of d 64, H 2, KV 1, hd 32 (attention 4,096 x 3 = 12,288
+# weights), V 100, and where they appear: dense FFN F 96; 8 experts of 48,
+# top 2; SSD expand 2 (inner 128), head 16 (8 heads), state 8, chunk 4
+ATTN = {"d_model": 64, "n_heads": 2, "n_kv_heads": 1, "head_dim": 32,
+        "vocab_size": 100, "d_ff": 96, "qk_norm": False, "dtype": "bfloat16"}
+EXPERTS = {"moe.n_experts": 8, "moe.top_k": 2, "moe.d_ff_expert": 48}
+SSD = {"ssm.expand": 2, "ssm.head_dim": 16, "ssm.d_state": 8, "ssm.chunk": 4}
+
+
+def test_an_expert_layer_counts_its_top_k_experts_and_its_router():
+    m = dict(ATTN, n_layers=1, **EXPERTS)
+    attn = 64 * 2 * 32 + 2 * 64 * 1 * 32 + 2 * 32 * 64
+    experts = 2 * 3 * 64 * 48 + 64 * 8  # top 2 of three matrices, router
+    assert attn == 12_288 and experts == 18_944
+    assert flops.layer_matmul_params(m) == 12_288 + 18_944
+    assert flops.matmul_params(m) == 31_232 + 64 * 100 == 37_632
+    assert flops.train_flops_per_token(m, 16) == (
+        6 * 37_632 + 3 * 4 * 1 * 2 * 32 * 16 * 0.5)
+    for decode in (lambda: flops.weight_bytes(m),
+                   lambda: flops.decode_round_flops(m, [5]),
+                   lambda: flops.decode_round_bytes(m, [5], 4)):
+        with pytest.raises(ValueError, match="dense attention layers only"):
+            decode()
+
+
+def test_an_ssd_layer_counts_its_projections_and_its_scan():
+    m = dict(ATTN, n_layers=1, family="ssm", **SSD)
+    in_proj = 64 * (2 * 128 + 2 * 8 + 8)  # z, x, B, C, dt
+    out_proj = 128 * 64
+    assert flops.ssd_params(m) == in_proj + out_proj == 17_920 + 8_192
+    assert flops.layer_matmul_params(m) == 26_112 + 3 * 64 * 96 == 44_544
+    # intra-chunk (half counted), chunk states and readout, per token
+    scan = (2 * 4 * 8 + 2 * 4 * 8 * 16) * 0.5 + 4 * 8 * 128
+    assert flops.ssd_scan_flops_per_token(m) == scan == 4_640
+    assert flops.train_flops_per_token(m, 16) == (
+        6 * (44_544 + 6_400) + 3 * 4_640)  # no attention pair term
+    assert flops.kv_bytes_per_token(m) == 0
+
+
+def test_a_gelu_ffn_counts_two_matrices():
+    m = dict(ATTN, n_layers=2, ffn_act="gelu")
+    assert flops.layer_matmul_params(m) == 12_288 + 2 * 64 * 96 == 24_576
+    assert flops.matmul_params(m) == 2 * 24_576 + 6_400 == 55_552
+    norms = 2 * 2 * 64 + 64
+    assert flops.weight_bytes(m) == (55_552 + norms) * 2
+    assert flops.decode_round_flops(m, [5]) == (
+        2 * 55_552 + 4 * 2 * 2 * 32 * 6)
+
+
+def test_a_hybrid_pattern_counts_each_layer_by_its_kind():
+    m = dict(ATTN, n_layers=8, family="hybrid", attn_every=4, attn_offset=1,
+             **EXPERTS, **SSD, **{"moe.every": 2, "moe.offset": 0})
+    assert flops.layer_pattern(m) == [
+        ("ssm", True), ("attn", False), ("ssm", True), ("ssm", False),
+        ("ssm", True), ("attn", False), ("ssm", True), ("ssm", False)]
+    ssd_experts = 26_112 + 18_944
+    attn_dense = 12_288 + 18_432
+    ssd_dense = 26_112 + 18_432
+    assert flops.matmul_params(m) == (
+        4 * ssd_experts + 2 * attn_dense + 2 * ssd_dense + 6_400) == 337_152
+    assert flops.train_flops_per_token(m, 16) == (
+        6 * 337_152 + 3 * 4 * 2 * 2 * 32 * 16 * 0.5 + 3 * 6 * 4_640)
+    assert flops.kv_bytes_per_token(m) == 2 * 2 * 1 * 32 * 2 == 256
+    # one page of 4 tokens, q and the output of the two attention layers
+    assert flops.paged_kernel_bytes(m, [0], 4) == 256 * 4 + 2 * 2 * 2 * 32 * 2
+
+
+@pytest.mark.parametrize("arch", ["dense", "moe", "hybrid"])
+def test_copied_flop_count_equals_the_programs(arch):
+    """The program's own count (``launch/roofline.py``) charges every
+    parameter that is no embedding row as a matmul weight; less the norms,
+    the SSD convolution and its per-head scalars it is the benchmark's."""
+    from functools import partial
+
+    from bench import program
+    from repro.configs.base import ShapeConfig
+    from repro.launch.roofline import model_flops
+    from repro.models.params import init_params
+
+    config = bt.tiny_config() if arch == "dense" else bt.arch_config(arch)
+    m = cells.model_sizes(config)
+    cfg = program.model_config(config, m)
+    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.PRNGKey(0))
+    other = sum(int(np.prod(v.shape)) for k, v in program.named_leaves(
+        shapes).items() if k.split("/")[-1] in (
+            "ln", "final_norm", "q_norm", "k_norm", "gate_norm", "conv_w",
+            "conv_b", "A_log", "D", "dt_bias"))
+    B, S = 4, 64
+    theirs = model_flops(cfg, ShapeConfig("t", S, B, "train")) / (B * S)
+    assert flops.train_flops_per_token(m, S) == pytest.approx(
+        theirs - 6 * other, rel=1e-12)
+
+
 # ---------------------------------------------------------------------------
 # peaks and the refusal to run off the chip
 # ---------------------------------------------------------------------------
